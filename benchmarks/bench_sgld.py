@@ -35,7 +35,10 @@ score recompute + the weighted feature sum), so
             table stream once per pass; the (m, K) score/weight tiles live
             and die in VMEM — that is the point of the fusion)
     ai     = flops / bytes
-    roofline_us = max(flops / PEAK_FLOPS_BF16, bytes / HBM_BW) · 1e6
+    roofline_us = max(flops / peak_flops, bytes / peak_hbm) · 1e6
+
+with the running chip's peaks from ``repro.launch.peaks``. CPU rows carry
+no roofline term (the host has no entry in that table).
 
 A full run also merges an ``"sgld"`` record into ``BENCH_7.json`` (rows +
 kernel-vs-xla / kernel-vs-autodiff / auto-vs-autodiff medians); ``--smoke``
@@ -56,7 +59,7 @@ import numpy as np
 from repro.core import fgts
 from repro.kernels.dueling_score import default_interpret
 from repro.kernels.sgld_update import MAX_K_FUSED, resolve_sgld_backend
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.peaks import peaks_for
 
 from .common import emit, merge_bench_json
 
@@ -72,11 +75,14 @@ SWEEP = [(k, m, d, c)
 SMOKE = [(64, 128, 256, 1), (256, 128, 256, 8)]
 
 
-def _cost_model(k, m, d, c):
+def _cost_model(k, m, d, c, peaks):
+    """flops, bytes, intensity and the roofline bound in us (None when
+    ``peaks`` is None: the host CPU has no roofline)."""
     flops = 10.0 * m * k * d * c
     bytes_ = 4.0 * c * (2.0 * m * d + 4.0 * k * d + 2.0 * d)
     ai = flops / bytes_
-    roofline_us = max(flops / PEAK_FLOPS_BF16, bytes_ / HBM_BW) * 1e6
+    roofline_us = None if peaks is None else max(
+        flops / peaks.flops_bf16, bytes_ / peaks.hbm_bytes_per_s) * 1e6
     return flops, bytes_, ai, roofline_us
 
 
@@ -124,12 +130,13 @@ def _time_interleaved(fns, *args, n=5):
 
 def run(smoke: bool = False, out: str | None = "BENCH_7.json"):
     rows, records = [], []
+    peaks = peaks_for(jax.devices()[0])
     # label the auto heuristic's pick in this bench's vocabulary
     resolved_label = {"fused": "kernel", "xla": "xla",
                       "autodiff": "autodiff"}
     for k, m, d, c in (SMOKE if smoke else SWEEP):
         sampler, keys, st, theta = _point(k, m, d, c)
-        flops, bytes_, ai, roof = _cost_model(k, m, d, c)
+        flops, bytes_, ai, roof = _cost_model(k, m, d, c, peaks)
         # Where "fused" resolves to the interpret lowering (host backends,
         # or K above MAX_K_FUSED), :kernel and :xla are bit-identical
         # programs — time once and report the shared number rather than
@@ -147,8 +154,9 @@ def run(smoke: bool = False, out: str | None = "BENCH_7.json"):
         err = float(jnp.max(jnp.abs(samples["kernel"]
                                     - samples["autodiff"])))
         base = f"sgld/K{k}_m{m}_d{d}_c{c}"
-        model = (f"flops={flops:.3e};bytes={bytes_:.3e};ai={ai:.1f};"
-                 f"roofline_us={roof:.2f}")
+        model = f"flops={flops:.3e};bytes={bytes_:.3e};ai={ai:.1f}"
+        if roof is not None:
+            model += f";roofline_us={roof:.2f}"
         rows.append(emit(f"{base}:kernel", secs["kernel"],
                          f"{model};max_err={err:.2e}"))
         xla_model = model + (";shared_with_kernel=1" if same_program else "")

@@ -16,7 +16,8 @@ all-reduce / reduce-scatter / all-to-all / collective-permute ops in the
 post-SPMD HLO, with ring-traffic multipliers {ar: 2x, others: 1x} — a
 first-order ICI model (documented in EXPERIMENTS.md).
 
-v5e: 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks are the target chip's row of ``repro.launch.peaks`` (v5e: 197 bf16
+TFLOP/s, 819 GB/s HBM, 1,600 Gbit/s ICI).
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ import os
 import time
 
 from repro.configs import ARCHS, SHAPES, get_arch
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.peaks import PEAKS, TARGET_KIND
 
 from .common import RESULTS, emit
 
@@ -76,9 +77,10 @@ def model_flops(arch: str, shape_name: str) -> float:
 def analyse(rec: dict) -> dict:
     tot = reconstruct_totals(rec)
     chips = rec["n_chips"]
-    t_c = tot["flops"] / PEAK_FLOPS_BF16
-    t_m = tot["bytes"] / HBM_BW
-    t_n = tot["coll"] / ICI_BW
+    peaks = PEAKS[TARGET_KIND]
+    t_c = tot["flops"] / peaks.flops_bf16
+    t_m = tot["bytes"] / peaks.hbm_bytes_per_s
+    t_n = tot["coll"] / peaks.ici_bytes_per_s
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
               key=lambda kv: kv[1])
     mf = model_flops(rec["arch"], rec["shape"])

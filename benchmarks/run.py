@@ -57,6 +57,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.fast:
         os.environ["REPRO_RUNS"] = "2"
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (bench_autopilot, bench_baselines, bench_delayed,
                    bench_dynamic_pool, bench_generalization, bench_kernels,

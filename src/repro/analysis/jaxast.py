@@ -23,7 +23,7 @@ JIT_WRAPPERS = {
     "jax.lax.scan", "jax.lax.cond", "jax.lax.switch", "jax.lax.while_loop",
     "jax.lax.fori_loop", "jax.lax.map", "jax.lax.associative_scan",
     "jax.experimental.pallas.pallas_call",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map", "jax.experimental.shard_map.shard_map",
 }
 
 # The subset that memoizes compiled programs keyed on operand structure —
@@ -31,7 +31,7 @@ JIT_WRAPPERS = {
 PROGRAM_BUILDERS = {
     "jax.jit", "jax.pmap",
     "jax.experimental.pallas.pallas_call",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map", "jax.experimental.shard_map.shard_map",
 }
 
 PRNG_SOURCES = {

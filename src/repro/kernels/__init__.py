@@ -13,6 +13,13 @@ no submodule grows its own copy.
 # back to scores + XLA argmax (router pools are K <= ~100 in practice).
 MAX_K_FUSED = 1024
 
+# Matmul precision inside the router kernels: f32 operands contract in f32.
+# Mosaic's default for f32 operands is a single bf16 pass, which moved the
+# fused SGLD gradient 8e-3 (relative) off its autodiff reference on a TPU
+# v5e; the interpret lowering, and so the "xla" backend on the CPU, is f32
+# either way.
+KERNEL_PRECISION = "highest"
+
 from .ops import (dueling_score_op, dueling_select_op, flash_attention_op,
                   rglru_scan_op, sgld_potential_op, ssd_scan_op)
 

@@ -19,8 +19,9 @@ present and falls back to interpret mode on host-only platforms. Override
 with the ``REPRO_PALLAS_INTERPRET`` env var ("1"/"0").
 
 ``dueling_select`` is the batched argmax epilogue: same score math, but the
-kernel reduces each (BB, K) tile directly to the routed pair (a1, a2) per
-query — K stays whole in VMEM, so no (J,B,K) score tensor ever reaches HBM.
+kernel reduces each arms-by-queries (K, BB) tile directly to the routed
+pair (a1, a2) per query — K stays whole in VMEM, so no (J,B,K) score tensor
+ever reaches HBM, and the pairs leave as lane-dense (1, BB) int32 rows.
 It also applies the serve-time cost tilt — a global (K,) penalty or a
 per-request (B,K) preference tilt, row-broadcast exactly like the activity
 mask — and the paper's force-distinct selection inside the kernel.
@@ -33,12 +34,13 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BB = 128
 DEFAULT_BK = 128
 # The fused-epilogue K ceiling lives on the package (single source of
 # truth, asserted by repro-lint's kernel-budget pass).
-from repro.kernels import MAX_K_FUSED  # noqa: E402
+from repro.kernels import KERNEL_PRECISION, MAX_K_FUSED  # noqa: E402
 
 _ACCEL_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 
@@ -75,12 +77,14 @@ def _dueling_kernel(x_ref, a_ref, th_ref, s_ref, *, n_theta: int):
     a = a_ref[...].astype(jnp.float32)              # (BK, d)
     th = th_ref[...].astype(jnp.float32)            # (J, d)
     den = jax.lax.dot_general(x * x, a * a, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=KERNEL_PRECISION)
     den = jnp.sqrt(jnp.maximum(den, 1e-24))         # (BB, BK)
     for j in range(n_theta):
         num = jax.lax.dot_general(x * th[j][None, :], a,
                                   (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+                                  preferred_element_type=jnp.float32,
+                                  precision=KERNEL_PRECISION)
         s_ref[j] = num / den
 
 
@@ -136,45 +140,60 @@ def posterior_scores(a: jax.Array, thetas: jax.Array, *,
     return dueling_score(ones, a, thetas, interpret=interpret)[:, 0, :]
 
 
+def _first_max(s: jax.Array, rows: jax.Array):
+    """(max, argmax) over axis 0 with ``jnp.argmax``'s first-index tie rule,
+    as a max plus a min over the row ids where the max is attained — two
+    sublane reductions that lower on every backend. Returns (1, BB) rows."""
+    top = jnp.max(s, axis=0, keepdims=True)
+    idx = jnp.min(jnp.where(s == top, rows, s.shape[0]), axis=0,
+                  keepdims=True)
+    return top, idx
+
+
 def _select_kernel(x_ref, a_ref, th_ref, tilt_ref, mask_ref, a1_ref, a2_ref,
                    *, k_valid: int, distinct: bool):
     """Score + argmax epilogue for one (BB,) block of queries.
 
-    K lives whole in VMEM; padded arms AND masked-out (inactive) arms are
-    set to -inf so they can never win the argmax. ``tilt`` is the
-    pre-multiplied score penalty, one row per query — a global cost tilt
-    (cost_tilt * cost_k broadcast over rows) or a per-request preference
-    tilt (pref_b * cost_k), subtracted from both samples' scores; ``mask``
-    is the int32 arm-activity mask, one row per query (dynamic model pools
-    flip whole columns at hot add/remove; the autopilot's candidate-quota
-    gate flips per-row slices — both without retracing).
+    The tile is laid out arms-by-queries, (K_pad, BB): the arm argmax is a
+    reduction over sublanes and each routed pair leaves the kernel as a
+    lane-dense (1, BB) int32 row. K lives whole in VMEM; padded arms AND
+    masked-out (inactive) arms are set to -inf so they can never win the
+    argmax. ``tilt`` is the pre-multiplied score penalty, one column per
+    query — a global cost tilt (cost_tilt * cost_k broadcast over queries)
+    or a per-request preference tilt (pref_b * cost_k), subtracted from
+    both samples' scores; ``mask`` is the int32 arm-activity mask, one
+    column per query (dynamic model pools flip whole arm rows at hot
+    add/remove; the autopilot's candidate-quota gate flips per-query
+    slices — both without retracing).
     """
     x = x_ref[...].astype(jnp.float32)              # (BB, d)
     a = a_ref[...].astype(jnp.float32)              # (K_pad, d)
     th = th_ref[...].astype(jnp.float32)            # (2, d)
-    tilt = tilt_ref[...].astype(jnp.float32)        # (BB, K_pad)
-    mask = mask_ref[...]                            # (BB, K_pad) int32
-    den = jax.lax.dot_general(x * x, a * a, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    den = jnp.sqrt(jnp.maximum(den, 1e-24))         # (BB, K_pad)
-    cols = jax.lax.broadcasted_iota(jnp.int32, den.shape, 1)
-    valid = (cols < k_valid) & (mask > 0)
+    tilt = tilt_ref[...].astype(jnp.float32)        # (K_pad, BB)
+    mask = mask_ref[...]                            # (K_pad, BB) int32
+    den = jax.lax.dot_general(a * a, x * x, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32,
+                              precision=KERNEL_PRECISION)
+    den = jnp.sqrt(jnp.maximum(den, 1e-24))         # (K_pad, BB)
+    rows = jax.lax.broadcasted_iota(jnp.int32, den.shape, 0)
+    valid = (rows < k_valid) & (mask > 0)
 
     def scores(j):
-        num = jax.lax.dot_general(x * th[j][None, :], a,
+        num = jax.lax.dot_general(a, x * th[j:j + 1, :],
                                   (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+                                  preferred_element_type=jnp.float32,
+                                  precision=KERNEL_PRECISION)
         return jnp.where(valid, num / den - tilt, -jnp.inf)
 
-    a1 = jnp.argmax(scores(0), axis=-1).astype(jnp.int32)       # (BB,)
+    _, a1 = _first_max(scores(0), rows)                         # (1, BB)
     s2 = scores(1)
     if distinct:
-        s2 = jnp.where(cols == a1[:, None], -jnp.inf, s2)
+        s2 = jnp.where(rows == a1, -jnp.inf, s2)
+    top2, a2 = _first_max(s2, rows)
     a1_ref[...] = a1
     # single-survivor pool: with one active arm a distinct pair is
     # impossible (s2 all -inf) — duel (a1, a1) instead of a masked arm
-    a2 = jnp.argmax(s2, axis=-1).astype(jnp.int32)
-    a2_ref[...] = mask_fallback_pair(s2, a1, a2)
+    a2_ref[...] = jnp.where(top2 == -jnp.inf, a1, a2)
 
 
 def dueling_select(x: jax.Array, a: jax.Array, thetas: jax.Array, *,
@@ -219,9 +238,11 @@ def dueling_select(x: jax.Array, a: jax.Array, thetas: jax.Array, *,
         a2 = jnp.argmax(s2, axis=-1).astype(jnp.int32)
         return a1, mask_fallback_pair(s2, a1, a2)
 
+    # queries ride the lanes: a block is either the whole (padded) batch
+    # or a multiple of 128 rows; arms ride the sublanes, padded to 8
     bb = min(bb, max(8, b))
     b_pad = -(-b // bb) * bb
-    k_pad = max(8, k)
+    k_pad = -(-max(8, k) // 8) * 8
     if b_pad != b:
         x = jnp.pad(x, ((0, b_pad - b), (0, 0)))
         tilt_i = jnp.pad(tilt_i, ((0, b_pad - b), (0, 0)))
@@ -238,13 +259,17 @@ def dueling_select(x: jax.Array, a: jax.Array, thetas: jax.Array, *,
             pl.BlockSpec((bb, d), lambda bi: (bi, 0)),
             pl.BlockSpec((k_pad, d), lambda bi: (0, 0)),
             pl.BlockSpec((2, d), lambda bi: (0, 0)),
-            pl.BlockSpec((bb, k_pad), lambda bi: (bi, 0)),
-            pl.BlockSpec((bb, k_pad), lambda bi: (bi, 0)),
+            pl.BlockSpec((k_pad, bb), lambda bi: (0, bi)),
+            pl.BlockSpec((k_pad, bb), lambda bi: (0, bi)),
         ],
-        out_specs=[pl.BlockSpec((bb,), lambda bi: (bi,)),
-                   pl.BlockSpec((bb,), lambda bi: (bi,))],
-        out_shape=[jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-                   jax.ShapeDtypeStruct((b_pad,), jnp.int32)],
+        out_specs=[pl.BlockSpec((1, bb), lambda bi: (0, bi)),
+                   pl.BlockSpec((1, bb), lambda bi: (0, bi))],
+        out_shape=[jax.ShapeDtypeStruct((1, b_pad), jnp.int32),
+                   jax.ShapeDtypeStruct((1, b_pad), jnp.int32)],
+        # at K = MAX_K_FUSED the f32 contractions' operand splits take ~18
+        # MiB of scoped VMEM, over the compiler's 16 MiB default (a v5e core
+        # has 128 MiB)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 << 20),
         interpret=interpret,
-    )(x, a, thetas, tilt_i, mask_i)
-    return a1[:b], a2[:b]
+    )(x, a, thetas, tilt_i.T, mask_i.T)
+    return a1[0, :b], a2[0, :b]

@@ -66,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels import MAX_K_FUSED
+from repro.kernels import KERNEL_PRECISION, MAX_K_FUSED
 
 from .dueling_score import _resolve_interpret, default_interpret
 
@@ -120,34 +120,41 @@ class _SgldSpec(NamedTuple):
 # ---------------------------------------------------------------------------
 
 def _tile_scores(theta, x, a):
-    """(bm, Kp) score tile via the two-matmul identity; also returns den."""
-    num = jax.lax.dot_general(x * theta[None, :], a, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    """(bm, Kp) score tile via the two-matmul identity; also returns den.
+    ``theta`` is a (1, d) row."""
+    num = jax.lax.dot_general(x * theta, a, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32,
+                              precision=KERNEL_PRECISION)
     den = jax.lax.dot_general(x * x, a * a, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=KERNEL_PRECISION)
     den = jnp.sqrt(jnp.maximum(den, 1e-24))
     return num / den, den
 
 
 def _tile_terms(mode, theta, x, a1, a2, y, duel, valid, pref, a, mask,
                 costs, *, j, eta, mu, k_valid):
-    """Summed potential contribution of one (bm,) row tile. ``pref`` (bm,)
-    and ``costs`` (Kp,) carry the per-row feel-good tilt t_ik = pref_i *
-    cost_k (all-zero when preference conditioning is off — a bitwise no-op
-    since the tilt only ever *subtracts*)."""
+    """Summed potential contribution of one row tile. Per-row operands are
+    (bm, 1) columns and per-arm operands (1, Kp) rows, so each broadcasts
+    against the (bm, Kp) score tile as it stands. ``pref`` and ``costs``
+    carry the per-row feel-good tilt t_ik = pref_i * cost_k (all-zero when
+    preference conditioning is off — a bitwise no-op since the tilt only
+    ever *subtracts*)."""
     s, _ = _tile_scores(theta, x, a)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    oh1 = cols == a1[:, None]
-    oh2 = cols == a2[:, None]
-    s1 = jnp.sum(jnp.where(oh1, s, 0.0), axis=1)     # exact one-hot gather
-    s2 = jnp.sum(jnp.where(oh2, s, 0.0), axis=1)
+    oh1 = cols == a1
+    oh2 = cols == a2
+    s1 = jnp.sum(jnp.where(oh1, s, 0.0), axis=1, keepdims=True)  # one-hot
+    s2 = jnp.sum(jnp.where(oh2, s, 0.0), axis=1, keepdims=True)  # gathers
     z = y * (s1 - s2)
     pref_ll = eta * jax.nn.softplus(-z)
     if mode == "fgts":
-        t = pref[:, None] * costs[None, :]           # (bm, Kp) tilt
-        live = (cols < k_valid) & (mask[None, :] > 0)
-        smax = jnp.max(jnp.where(live, s - t, -jnp.inf), axis=1)
-        t_opp = jnp.sum(jnp.where(oh2 if j == 1 else oh1, t, 0.0), axis=1)
+        t = pref * costs                             # (bm, Kp) tilt
+        live = (cols < k_valid) & (mask > 0)
+        smax = jnp.max(jnp.where(live, s - t, -jnp.inf), axis=1,
+                       keepdims=True)
+        t_opp = jnp.sum(jnp.where(oh2 if j == 1 else oh1, t, 0.0), axis=1,
+                        keepdims=True)
         opp = (s2 if j == 1 else s1) - t_opp
         # pref-stratified feel-good weight mu / (1 + pref): tilted rows get
         # proportionally less optimism so their cheap-end feel-good doesn't
@@ -164,88 +171,105 @@ def _tile_terms(mode, theta, x, a1, a2, y, duel, valid, pref, a, mask,
 
 def _tile_grad(mode, theta, x, a1, a2, y, duel, valid, pref, a, mask,
                costs, g, *, j, eta, mu, k_valid):
-    """d(tile potential)/dtheta: weights W on the score matrix, then
-    dtheta = g * sum_i x_i * ((W_i / den_i) @ A). The tilt t_ik is
-    theta-independent, so it only moves *which* column wins the feel-good
-    max (the argmax one-hot is taken on the tilted scores); the weight
-    values are unchanged."""
+    """d(tile potential)/dtheta as a (1, d) row: weights W on the score
+    matrix, then dtheta = g * sum_i x_i * ((W_i / den_i) @ A). The tilt
+    t_ik is theta-independent, so it only moves *which* column wins the
+    feel-good max (the argmax one-hot is taken on the tilted scores); the
+    weight values are unchanged. ``g`` is the (1, 1) cotangent."""
     s, den = _tile_scores(theta, x, a)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    oh1b = cols == a1[:, None]
-    oh2b = cols == a2[:, None]
-    s1 = jnp.sum(jnp.where(oh1b, s, 0.0), axis=1)
-    s2 = jnp.sum(jnp.where(oh2b, s, 0.0), axis=1)
+    oh1b = cols == a1
+    oh2b = cols == a2
+    s1 = jnp.sum(jnp.where(oh1b, s, 0.0), axis=1, keepdims=True)
+    s2 = jnp.sum(jnp.where(oh2b, s, 0.0), axis=1, keepdims=True)
     z = y * (s1 - s2)
     dz = eta * (-jax.nn.sigmoid(-z)) * y             # d pref / d(s1 - s2)
     oh1 = oh1b.astype(jnp.float32)
     oh2 = oh2b.astype(jnp.float32)
     if mode == "fgts":
-        w = dz[:, None] * (oh1 - oh2)
-        t = pref[:, None] * costs[None, :]
-        live = (cols < k_valid) & (mask[None, :] > 0)
+        w = dz * (oh1 - oh2)
+        t = pref * costs
+        live = (cols < k_valid) & (mask > 0)
         sm = jnp.where(live, s - t, -jnp.inf)
-        smax = jnp.max(sm, axis=1)
+        smax = jnp.max(sm, axis=1, keepdims=True)
         # tie-split argmax one-hot: jnp.max's VJP spreads the cotangent
         # evenly over tied maxima, so the hand gradient must too
-        eq = ((sm == smax[:, None]) & live).astype(jnp.float32)
-        cnt = jnp.maximum(jnp.sum(eq, axis=1), 1.0)
+        eq = ((sm == smax) & live).astype(jnp.float32)
+        cnt = jnp.maximum(jnp.sum(eq, axis=1, keepdims=True), 1.0)
         # per-row feel-good weight — must mirror _tile_terms exactly
         mu_row = mu / (1.0 + jnp.maximum(pref, 0.0))
-        w = w - mu_row[:, None] * (eq / cnt[:, None])
-        w = w + mu_row[:, None] * (oh2 if j == 1 else oh1)
+        w = w - mu_row * (eq / cnt)
+        w = w + mu_row * (oh2 if j == 1 else oh1)
     else:
         dclick = eta * jnp.where(y > 0.5, -jax.nn.sigmoid(-s1),
                                  jax.nn.sigmoid(s1))
-        w = jnp.where((duel > 0)[:, None], dz[:, None] * (oh1 - oh2),
-                      dclick[:, None] * oh1)
-    w = w * valid[:, None]
+        w = jnp.where(duel > 0, dz * (oh1 - oh2), dclick * oh1)
+    w = w * valid
     r = jax.lax.dot_general(w / den, a, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bm, d)
-    return g * jnp.sum(x * r, axis=0)
+                            preferred_element_type=jnp.float32,
+                            precision=KERNEL_PRECISION)  # (bm, d)
+    return g * jnp.sum(x * r, axis=0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
 # Pallas kernels + drivers (forward and backward)
 # ---------------------------------------------------------------------------
+# Every operand and output is 2-D with its last two block dims either whole
+# or (8, 128)-aligned, as the TPU compiler requires: theta a (1, d) row, the
+# per-row minibatch fields (bm, 1) columns, the arm mask and costs (1, Kp)
+# rows. Each tile writes its partial into its own output slot — the forward
+# a lane-broadcast (1, 128) row, the backward a (1, d) row — and the slots
+# are summed outside the kernel, so ``vmap`` over SGLD chains lifts to a
+# leading grid axis instead of racing on an accumulator.
+
+_LANES = 128
+
 
 def _fwd_kernel(th_ref, x_ref, a1_ref, a2_ref, y_ref, du_ref, v_ref, p_ref,
                 a_ref, m_ref, c_ref, o_ref, *, mode, j, eta, mu, k_valid):
-    o_ref[0, 0] = _tile_terms(
+    total = _tile_terms(
         mode, th_ref[...], x_ref[...], a1_ref[...], a2_ref[...], y_ref[...],
         du_ref[...], v_ref[...], p_ref[...], a_ref[...], m_ref[...],
         c_ref[...], j=j, eta=eta, mu=mu, k_valid=k_valid)
+    o_ref[...] = jnp.broadcast_to(total, o_ref.shape)
 
 
 def _bwd_kernel(g_ref, th_ref, x_ref, a1_ref, a2_ref, y_ref, du_ref, v_ref,
                 p_ref, a_ref, m_ref, c_ref, o_ref, *, mode, j, eta, mu,
                 k_valid):
-    o_ref[0, :] = _tile_grad(
+    o_ref[...] = _tile_grad(
         mode, th_ref[...], x_ref[...], a1_ref[...], a2_ref[...], y_ref[...],
         du_ref[...], v_ref[...], p_ref[...], a_ref[...], m_ref[...],
-        c_ref[...], g_ref[0, 0],
+        c_ref[...], g_ref[...],
         j=j, eta=eta, mu=mu, k_valid=k_valid)
 
 
 def _row_specs(spec, d, kp):
     bm = spec.bm
+    col = pl.BlockSpec((bm, 1), lambda i: (i, 0))
     return [
-        pl.BlockSpec((d,), lambda i: (0,)),          # theta
+        pl.BlockSpec((1, d), lambda i: (0, 0)),      # theta
         pl.BlockSpec((bm, d), lambda i: (i, 0)),     # x
-        pl.BlockSpec((bm,), lambda i: (i,)),         # a1
-        pl.BlockSpec((bm,), lambda i: (i,)),         # a2
-        pl.BlockSpec((bm,), lambda i: (i,)),         # y
-        pl.BlockSpec((bm,), lambda i: (i,)),         # is_duel
-        pl.BlockSpec((bm,), lambda i: (i,)),         # valid
-        pl.BlockSpec((bm,), lambda i: (i,)),         # pref (feel-good tilt)
+        col, col, col,                               # a1, a2, y
+        col, col, col,                               # is_duel, valid, pref
         pl.BlockSpec((kp, d), lambda i: (0, 0)),     # a_emb
-        pl.BlockSpec((kp,), lambda i: (0,)),         # arm mask
-        pl.BlockSpec((kp,), lambda i: (0,)),         # arm costs
+        pl.BlockSpec((1, kp), lambda i: (0, 0)),     # arm mask
+        pl.BlockSpec((1, kp), lambda i: (0, 0)),     # arm costs
     ]
 
 
 def _statics(spec):
     return dict(mode=spec.mode, j=spec.j, eta=spec.eta, mu=spec.mu,
                 k_valid=spec.k_valid)
+
+
+def _kernel_operands(theta, x, a1, a2, y, du, valid, pref, a_emb, mask,
+                     costs):
+    """The custom-VJP operands in the kernels' 2-D layouts."""
+    col = lambda v: v.reshape(-1, 1)
+    row = lambda v: v.reshape(1, -1)
+    return (row(theta), x, col(a1), col(a2), col(y), col(du), col(valid),
+            col(pref), a_emb, row(mask), row(costs))
 
 
 def _forward(spec, theta, x, a1, a2, y, du, valid, pref, a_emb, mask, costs):
@@ -256,11 +280,12 @@ def _forward(spec, theta, x, a1, a2, y, du, valid, pref, a_emb, mask, costs):
         functools.partial(_fwd_kernel, **_statics(spec)),
         grid=(n,),
         in_specs=_row_specs(spec, d, kp),
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, _LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, _LANES), jnp.float32),
         interpret=spec.interpret,
-    )(theta, x, a1, a2, y, du, valid, pref, a_emb, mask, costs)
-    return jnp.sum(partials)
+    )(*_kernel_operands(theta, x, a1, a2, y, du, valid, pref, a_emb, mask,
+                        costs))
+    return jnp.sum(partials[:, 0, 0])
 
 
 def _backward(spec, g, theta, x, a1, a2, y, du, valid, pref, a_emb, mask,
@@ -274,11 +299,12 @@ def _backward(spec, g, theta, x, a1, a2, y, du, valid, pref, a_emb, mask,
         grid=(n,),
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0))]
         + _row_specs(spec, d, kp),
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), jnp.float32),
         interpret=spec.interpret,
-    )(g2, theta, x, a1, a2, y, du, valid, pref, a_emb, mask, costs)
-    return jnp.sum(partials, axis=0)
+    )(g2, *_kernel_operands(theta, x, a1, a2, y, du, valid, pref, a_emb,
+                            mask, costs))
+    return jnp.sum(partials[:, 0, :], axis=0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

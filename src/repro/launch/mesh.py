@@ -8,22 +8,26 @@ state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-# v5e hardware constants (per chip) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW = 50e9                 # bytes/s per link
+
+def _auto_axes(n: int) -> tuple:
+    """Every mesh here has Auto axes: the programs place arrays through
+    in/out shardings, sharding constraints and shard_map (GSPMD style).
+    ``jax.make_mesh`` defaults to Explicit axes, which carry shardings in
+    the array types and reject this code's gathers and scatters."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto_axes(len(axes)))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over however many devices exist (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over the first data * model devices (tests, one host)."""
+    return jax.make_mesh((data, model), ("data", "model"), _auto_axes(2))
 
 
 def n_chips(mesh) -> int:

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS
 from repro.core import baselines, fgts
 from repro.core import model_pool as mp
@@ -186,6 +187,7 @@ def main():
                          "trade-off (needs a preference-aware policy; all "
                          "built-ins qualify when the pool is dynamic)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     pref_sampler = None
     if args.pref_dist:

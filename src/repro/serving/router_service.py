@@ -39,7 +39,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import autopilot as ap
@@ -446,8 +445,8 @@ class RouterService:
         # act: batch partitioned, state + key replicated. shard_map hands
         # each device its local shard — every device recomputes the
         # identical posterior refresh (same key, same replicated state) and
-        # scores only its rows; check_rep is off because the rep-rule
-        # system cannot prove the refresh is replicated through random ops.
+        # scores only its rows; check_vma is off because the varying-axes
+        # check cannot prove the refresh is replicated through random ops.
         # Factory-built policies default to GSPMD in_shardings traced under
         # partitionable threefry instead: per-row randomness then comes out
         # decorrelated across shards and invariant to the mesh size (the
@@ -462,10 +461,11 @@ class RouterService:
         act_extra = (P(bx),) if record else ()
         out_extra = (row,) if record else ()
         if use_sm:
-            act = shard_map(act_core, mesh=mesh,
-                            in_specs=(P(), P(), rr.query_batch_spec(mesh)),
-                            out_specs=(P(), P(bx), P(bx)) + act_extra,
-                            check_rep=False)
+            act = jax.shard_map(act_core, mesh=mesh,
+                                in_specs=(P(), P(),
+                                          rr.query_batch_spec(mesh)),
+                                out_specs=(P(), P(bx), P(bx)) + act_extra,
+                                check_vma=False)
         else:
             def act(key, state, x, _act=act_core):
                 with jax.threefry_partitionable(True):
@@ -478,12 +478,12 @@ class RouterService:
         self._act_pref = None
         if act_pref_core is not None:
             if use_sm:
-                act_p = shard_map(
+                act_p = jax.shard_map(
                     act_pref_core, mesh=mesh,
                     in_specs=(P(), P(), rr.query_batch_spec(mesh),
                               rr.pref_spec(mesh)),
                     out_specs=(P(), P(bx), P(bx)) + act_extra,
-                    check_rep=False)
+                    check_vma=False)
             else:
                 def act_p(key, state, x, pref, _ap=act_pref_core):
                     with jax.threefry_partitionable(True):
@@ -683,29 +683,31 @@ class RouterService:
                                              mask, sidx(), n_shards,
                                              prop=prop, cat=cat)
 
-                enq = shard_map(enq_local, mesh=mesh,
-                                in_specs=(pspec, qryp, rowp, rowp, P(),
-                                          rowp, rowp, rowp, rowp),
-                                out_specs=(pspec, rowp), check_rep=False)
+                enq = jax.shard_map(enq_local, mesh=mesh,
+                                    in_specs=(pspec, qryp, rowp, rowp, P(),
+                                              rowp, rowp, rowp, rowp),
+                                    out_specs=(pspec, rowp),
+                                    check_vma=False)
             else:
                 def enq_local(q, x, a1, a2, now, pref, mask):
                     return fq.enqueue_stream(q, x, a1, a2, now, pref,
                                              mask, sidx(), n_shards)
 
-                enq = shard_map(enq_local, mesh=mesh,
-                                in_specs=(pspec, qryp, rowp, rowp, P(),
-                                          rowp, rowp),
-                                out_specs=(pspec, rowp), check_rep=False)
+                enq = jax.shard_map(enq_local, mesh=mesh,
+                                    in_specs=(pspec, qryp, rowp, rowp, P(),
+                                              rowp, rowp),
+                                    out_specs=(pspec, rowp),
+                                    check_vma=False)
 
             def rsv_local(q, tickets, y, mask, now):
                 return fq.resolve_stream(q, tickets, y, mask, now, sidx(),
                                          n_shards,
                                          max_age=cfg.feedback_expiry)
 
-            rsv = shard_map(rsv_local, mesh=mesh,
-                            in_specs=(pspec, rowp, rowp, rowp, P()),
-                            out_specs=(pspec, rr.resolved_specs(mesh)),
-                            check_rep=False)
+            rsv = jax.shard_map(rsv_local, mesh=mesh,
+                                in_specs=(pspec, rowp, rowp, rowp, P()),
+                                out_specs=(pspec, rr.resolved_specs(mesh)),
+                                check_vma=False)
 
         # fused per-bucket programs. The tick advances ON DEVICE (now + 1)
         # and is threaded through every program as a donated passthrough,

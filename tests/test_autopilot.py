@@ -425,40 +425,51 @@ def test_wrapper_counts_candidate_duels_from_feedback():
 def test_cost_governor_holds_budget_in_env_loop():
     """Make the expensive arm the *best* arm, so an unconstrained router
     gravitates to it; under a budget the governor's lambda must tilt
-    routing until the realized duel cost sits at (or under) budget."""
+    routing until the realized duel cost sits at (or under) budget.
+
+    The best arm's edge in these random environments is small, so one
+    environment's late-window cost is a noisy draw either side of the
+    budget: each property is asserted on the mean over 16 seeded
+    environments (one vmapped program per arm of the comparison), over a
+    horizon long enough for the posterior to find the best arm."""
     from repro.core import ccft
-    k_a, k_th, k_x = jax.random.split(jax.random.fold_in(KEY, 31), 3)
-    a_emb = jax.random.normal(k_a, (K, DIM))
-    theta_star = jax.random.normal(k_th, (DIM,))
-    x = jax.random.normal(k_x, (T, DIM))
-    utils = jax.vmap(lambda xi: ccft.scores_all(xi, a_emb, theta_star))(x)
-    utils = (utils - utils.min()) / (utils.max() - utils.min())
-    best = int(jnp.argmax(utils.mean(axis=0)))
-    costs = jnp.full((K,), 0.1).at[best].set(2.0)
-    e = env_lib.EnvData(x=x, utils=utils)
+    horizon, seeds = 256, 16
     budget = 0.4
 
-    def curve(cfg):
+    def late_cost(key, cfg):
+        k_a, k_th, k_x = jax.random.split(jax.random.fold_in(key, 31), 3)
+        a_emb = jax.random.normal(k_a, (K, DIM))
+        theta_star = jax.random.normal(k_th, (DIM,))
+        x = jax.random.normal(k_x, (horizon, DIM))
+        utils = jax.vmap(
+            lambda xi: ccft.scores_all(xi, a_emb, theta_star))(x)
+        utils = (utils - utils.min()) / (utils.max() - utils.min())
+        best = jnp.argmax(utils.mean(axis=0))
+        costs = jnp.where(jnp.arange(K) == best, 2.0, 0.1)
+        e = env_lib.EnvData(x=x, utils=utils)
         pol = ap.wrap(policy_lib.fgts_policy(
             mp.init_pool(a_emb, costs),
             _cfg(eta=8.0, sgld_steps=6, sgld_minibatch=16)), cfg)
         _, state, aux = env_lib.run(
-            KEY, e, pol, batch=BATCH,
+            key, e, pol, batch=BATCH,
             aux_fn=lambda s, i, j: jnp.mean(
                 0.5 * (mp.get_pool(s).costs[i] + mp.get_pool(s).costs[j])))
-        return state, np.asarray(aux)
+        n = aux.shape[0]
+        return jnp.mean(aux[3 * n // 4:]), state.ctrl.lam
 
-    st_free, cost_free = curve(ap.AutopilotConfig(every=2, tau=2.0))
-    st_gov, cost_gov = curve(ap.AutopilotConfig(every=2, tau=2.0,
-                                                budget=budget,
-                                                budget_lr=1.0))
-    n = len(cost_gov)
-    late_free = float(cost_free[3 * n // 4:].mean())
-    late_gov = float(cost_gov[3 * n // 4:].mean())
-    assert late_free > budget            # unconstrained: over budget
-    assert late_gov <= budget * 1.1      # governed: held at budget
-    assert float(st_gov.ctrl.lam) > 0.0
-    assert float(st_free.ctrl.lam) == 0.0
+    def sweep(cfg):
+        keys = jax.random.split(KEY, seeds)
+        cost, lam = jax.jit(jax.vmap(lambda k: late_cost(k, cfg)))(keys)
+        return np.asarray(cost), np.asarray(lam)
+
+    late_free, lam_free = sweep(ap.AutopilotConfig(every=2, tau=2.0))
+    late_gov, lam_gov = sweep(ap.AutopilotConfig(every=2, tau=2.0,
+                                                 budget=budget,
+                                                 budget_lr=1.0))
+    assert late_free.mean() > budget            # unconstrained: over budget
+    assert late_gov.mean() <= budget * 1.1      # governed: held at budget
+    assert lam_gov.mean() > 0.0
+    assert (lam_free == 0.0).all()
 
 
 # ---------------------------------------------------------------------------

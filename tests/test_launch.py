@@ -38,9 +38,9 @@ def test_input_specs_build_for_all_combos(arch, shape_name):
     if not applicable(arch, shape_name):
         pytest.skip("long_500k on full-attention arch (noted skip)")
     cfg = get_arch(arch, shape_name)
-    for mesh in (jax.sharding.AbstractMesh((("data", 16), ("model", 16))),
+    for mesh in (jax.sharding.AbstractMesh((16, 16), ("data", "model")),
                  jax.sharding.AbstractMesh(
-                     (("pod", 2), ("data", 16), ("model", 16)))):
+                     (2, 16, 16), ("pod", "data", "model"))):
         args, in_sh, out_sh, step = steps_lib.input_specs(
             cfg, SHAPES[shape_name], mesh)
         assert callable(step)

@@ -19,7 +19,7 @@ from repro.sharding import rules
 
 def fake_mesh(shape=(16, 16), axes=("data", "model")):
     """Abstract mesh for spec construction (no devices needed)."""
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
@@ -84,15 +84,19 @@ def test_production_mesh_shapes_monkeypatched(monkeypatch):
     entrypoint)."""
     calls = {}
 
-    def fake_make_mesh(shape, axes):
+    def fake_make_mesh(shape, axes, axis_types=None):
         calls["shape"], calls["axes"] = shape, axes
+        calls["types"] = axis_types
         return "mesh"
 
+    auto = jax.sharding.AxisType.Auto
     monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
     mesh_lib.make_production_mesh()
-    assert calls == {"shape": (16, 16), "axes": ("data", "model")}
+    assert calls == {"shape": (16, 16), "axes": ("data", "model"),
+                     "types": (auto, auto)}
     mesh_lib.make_production_mesh(multi_pod=True)
-    assert calls == {"shape": (2, 16, 16), "axes": ("pod", "data", "model")}
+    assert calls == {"shape": (2, 16, 16), "axes": ("pod", "data", "model"),
+                     "types": (auto, auto, auto)}
 
 
 def test_reduced_arch_lowers_on_real_mesh():
