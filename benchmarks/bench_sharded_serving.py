@@ -61,15 +61,10 @@ def _make_service(batch: int, mesh) -> RouterService:
                          mesh=mesh)
 
 
-def _throughput(svc: RouterService, batch: int, key) -> tuple:
+def _throughput(svc: RouterService, batch: int, key) -> float:
     """Steady-state queries/sec over the act -> enqueue -> resolve -> update
-    loop (feedback redeemed one round late, the async serving shape).
-
-    Syncs only at the measurement boundaries: the timed region issues every
-    call async, so ``t_disp`` (clock when the last call has been *issued*)
-    splits the wall time into host dispatch vs device compute drain —
-    dispatch_frac near 0 means the host keeps the devices fed, near 1
-    means the loop is dispatch-bound. Returns (qps, dispatch_frac)."""
+    loop (feedback redeemed one round late, the async serving shape),
+    synced only at the measurement boundaries."""
     xs = [jax.random.normal(jax.random.fold_in(key, i), (batch, DIM))
           for i in range(ROUNDS + WARMUP)]
     pending = None
@@ -82,10 +77,9 @@ def _throughput(svc: RouterService, batch: int, key) -> tuple:
         if pending is not None:
             svc.feedback_batch(pending, jnp.ones((batch,), jnp.float32))
         pending = tickets
-    t_disp = time.time()
     jax.block_until_ready(svc.state)
     t1 = time.time()
-    return ROUNDS * batch / (t1 - t0), (t_disp - t0) / (t1 - t0)
+    return ROUNDS * batch / (t1 - t0)
 
 
 def run(seed: int = SEED):
@@ -109,25 +103,22 @@ def run(seed: int = SEED):
     for batch in BATCHES:
         for label, mesh in grids:
             svc = _make_service(batch, mesh)
-            qps, disp = _throughput(svc, batch, key)
-            table[(batch, label)] = (qps, disp)
+            qps = _throughput(svc, batch, key)
+            table[(batch, label)] = qps
             rows.append(emit(f"sharded/serve_b{batch}_dev{label}",
-                             1.0 / qps,
-                             f"qps={qps:.0f};dispatch_frac={disp:.2f}"))
+                             1.0 / qps, f"qps={qps:.0f}"))
 
     dev_cols = [g[0] for g in grids]
-    print(f"\nsharded serving throughput (queries/sec and host-dispatch "
-          f"share of wall time, {ROUNDS} timed rounds, feedback lag 1 "
-          f"round, syncs at measurement boundaries only)")
-    print(f"{'batch':<8}" + "".join(f"{'dev=' + c:>18}" for c in dev_cols)
+    print(f"\nsharded serving throughput (queries/sec, {ROUNDS} timed "
+          f"rounds, feedback lag 1 round, syncs at measurement boundaries "
+          f"only)")
+    print(f"{'batch':<8}" + "".join(f"{'dev=' + c:>12}" for c in dev_cols)
           + (f"{'speedup':>10}" if len(dev_cols) > 1 else ""))
     for batch in BATCHES:
         line = f"{batch:<8}" + "".join(
-            f"{table[(batch, c)][0]:>10.0f} d={table[(batch, c)][1]:.2f}"
-            for c in dev_cols)
+            f"{table[(batch, c)]:>12.0f}" for c in dev_cols)
         if len(dev_cols) > 1:
-            speedup = (table[(batch, dev_cols[-1])][0]
-                       / table[(batch, "1")][0])
+            speedup = table[(batch, dev_cols[-1])] / table[(batch, "1")]
             line += f"{speedup:>10.2f}"
         print(line)
     return rows

@@ -120,6 +120,7 @@ def dueling_score(x: jax.Array, a: jax.Array, thetas: jax.Array, *,
         out_specs=pl.BlockSpec((j, bb, bk), lambda bi, ki: (0, bi, ki)),
         out_shape=jax.ShapeDtypeStruct((j, b_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name="dueling_score",
     )(x, a, thetas)
     return out[:, :b, :k]
 
@@ -271,5 +272,6 @@ def dueling_select(x: jax.Array, a: jax.Array, thetas: jax.Array, *,
         # has 128 MiB)
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 << 20),
         interpret=interpret,
+        name="dueling_select",
     )(x, a, thetas, tilt_i.T, mask_i.T)
     return a1[0, :b], a2[0, :b]

@@ -283,6 +283,7 @@ def _forward(spec, theta, x, a1, a2, y, du, valid, pref, a_emb, mask, costs):
         out_specs=pl.BlockSpec((None, 1, _LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1, _LANES), jnp.float32),
         interpret=spec.interpret,
+        name="sgld_potential_fwd",
     )(*_kernel_operands(theta, x, a1, a2, y, du, valid, pref, a_emb, mask,
                         costs))
     return jnp.sum(partials[:, 0, 0])
@@ -302,6 +303,7 @@ def _backward(spec, g, theta, x, a1, a2, y, du, valid, pref, a_emb, mask,
         out_specs=pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1, d), jnp.float32),
         interpret=spec.interpret,
+        name="sgld_potential_bwd",
     )(g2, *_kernel_operands(theta, x, a1, a2, y, du, valid, pref, a_emb,
                             mask, costs))
     return jnp.sum(partials[:, 0, :], axis=0)

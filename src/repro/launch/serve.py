@@ -523,6 +523,11 @@ def _serve_stream(args, spec, buckets, svc, skills, row_of_slot, cc,
           f"(adaptive: {'yes' if late < early else 'no'}) "
           f"routed={stats['n_routed']} folded={stats['n_folded']} "
           f"unresolved={stats['pending']}")
+    # host compiles per router.* span since construction: a count that
+    # grows between runs of the same traffic is a recompile in serving
+    print("[serve] compiles " + " ".join(
+        f"{k}={n} ({stats['compile_s'][k]:.2f}s)"
+        for k, n in sorted(stats["compiles"].items())))
 
 
 if __name__ == "__main__":

@@ -53,6 +53,7 @@ from repro.refresh.trainer import RefreshConfig
 from repro.sharding import routing_rules as rr
 from . import feedback_queue as fq
 from . import stream
+from . import telemetry
 
 # Donated argnums of the streaming AOT bucket programs (``buckets=...``):
 # buffer donation on the pending ring, the policy/posterior state, the tick
@@ -259,6 +260,8 @@ class RouterService:
             self.pending = fq.init_pending(capacity, self.a_emb.shape[1])
         self.tick = 0                  # route_batch calls (the service clock)
         self.n_routed = 0
+        # host totals of the router.* spans (telemetry): compiles per layer
+        self._compile_counts = telemetry.CompileCounts()
         # online representation refresh: the exportable duel-log ring rides
         # next to the policy state (replicated under a mesh) and is folded
         # inside the jitted feedback programs; None when refresh is off —
@@ -958,9 +961,15 @@ class RouterService:
             raise RuntimeError(
                 "route_stream needs RouterServiceConfig(buckets=...): the "
                 "tick-batch service compiles no AOT bucket programs")
-        x = jnp.asarray(x, jnp.float32)
-        n = x.shape[0]
+        n = np.shape(x)[0]
         b = stream.bucket_for(n, self.buckets)
+        with telemetry.span("route", self._compile_counts, tick=self.tick,
+                            n=n, bucket=b):
+            return self._route_bucket(x, n, b, prefs, cats)
+
+    def _route_bucket(self, x, n: int, b: int, prefs, cats):
+        """``route_stream``'s work inside its span: n live rows, bucket b."""
+        x = jnp.asarray(x, jnp.float32)
         xb = self._pad_batch(x, b, "route_stream")
         mask = self._stream_mask(b, n)
         if prefs is None:
@@ -1019,14 +1028,20 @@ class RouterService:
             raise RuntimeError(
                 "feedback_stream needs RouterServiceConfig(buckets=...): "
                 "the tick-batch service compiles no AOT bucket programs")
+        if np.shape(tickets) != np.shape(y):
+            raise ValueError(
+                f"feedback_stream: tickets shape {np.shape(tickets)} != "
+                f"votes shape {np.shape(y)} — one vote per delivered ticket")
+        n = np.shape(tickets)[0]
+        b = stream.bucket_for(n, self.buckets)
+        with telemetry.span("feedback", self._compile_counts,
+                            tick=self.tick, n=n, bucket=b):
+            return self._feedback_bucket(tickets, y, n, b)
+
+    def _feedback_bucket(self, tickets, y, n: int, b: int):
+        """``feedback_stream``'s work inside its span: n votes, bucket b."""
         tickets = jnp.asarray(tickets, jnp.int32)
         y = jnp.asarray(y, jnp.float32)
-        if tickets.shape != y.shape:
-            raise ValueError(
-                f"feedback_stream: tickets shape {tickets.shape} != votes "
-                f"shape {y.shape} — one vote per delivered ticket")
-        n = tickets.shape[0]
-        b = stream.bucket_for(n, self.buckets)
         tk = self._pad_batch(tickets, b, "feedback_stream")
         yb = self._pad_batch(y, b, "feedback_stream")
         mask = self._stream_mask(b, n)
@@ -1070,7 +1085,15 @@ class RouterService:
         return k
 
     def embed(self, tokens: jax.Array, mask: jax.Array) -> jax.Array:
-        return encode(self.enc_params, tokens, mask, self.enc_cfg)
+        """(B, L) tokens and pooling mask -> (B, d) query embeddings, in a
+        ``router.embed`` span with ``bucket`` B and, where the mask is on
+        the host, ``n``, the rows with a token (a device mask is not read,
+        and the span then has no ``n``)."""
+        live = ({"n": np.count_nonzero(mask.any(axis=1))}
+                if isinstance(mask, np.ndarray) else {})
+        with telemetry.span("embed", self._compile_counts, tick=self.tick,
+                            bucket=np.shape(tokens)[0], **live):
+            return encode(self.enc_params, tokens, mask, self.enc_cfg)
 
     def route_batch(self, x: jax.Array, prefs: jax.Array | None = None,
                     cats: jax.Array | None = None):
@@ -1307,7 +1330,9 @@ class RouterService:
                  fq.pending_count(self.pending)))
         out = {"tick": self.tick, "n_routed": self.n_routed,
                "n_folded": int(n_folded), "duel_cost": float(duel_cost),
-               "pending": int(pending)}
+               "pending": int(pending),
+               "compiles": dict(self._compile_counts.compiles),
+               "compile_s": dict(self._compile_counts.compile_s)}
         if self.refresh_on:
             out["duels_logged"] = int(logged)
             out["table_swaps"] = self._table_swaps

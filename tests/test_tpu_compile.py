@@ -6,7 +6,8 @@ serving widths: K=11 RouterBench arms, d=782 (a 768-wide query encoder plus
 2 x 7 metadata columns). They catch what interpret mode cannot — block
 shapes the TPU tiling refuses, operand layouts XLA and Mosaic disagree on,
 scalar stores to vector memory. Each test asserts that the kernel is in the
-compiled program (``tpu_custom_call``).
+compiled program (``tpu_custom_call``) under its ``pallas_call`` name, which
+a profile of the chip shows as the instruction's name.
 
 Only one process may hold libtpu, and it keeps it until it exits, so the
 topology is described inside a module-scoped fixture (never at import):
@@ -55,6 +56,14 @@ def _compiled_hlo(fn, shapes, sharding):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernels_named(hlo, name):
+    """Instruction names of the compiled kernels whose name holds ``name``
+    (a VJP prefixes the transforms: ``transpose_jvp_<name>__.N``)."""
+    insts = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    return [i for i in insts if name in i]
+
+
 @pytest.mark.parametrize("per_row", [False, True], ids=["mask_K", "mask_BK"])
 @pytest.mark.parametrize("b", [8, 256, 4096])
 def test_dueling_select_compiles(one_chip, b, per_row):
@@ -71,6 +80,7 @@ def test_dueling_select_compiles(one_chip, b, per_row):
                                 (mshape, jnp.bool_), (mshape, f32)],
                         one_chip)
     assert "tpu_custom_call" in hlo
+    assert _kernels_named(hlo, "dueling_select")
 
 
 def test_dueling_select_compiles_at_max_k(one_chip):
@@ -85,6 +95,7 @@ def test_dueling_select_compiles_at_max_k(one_chip):
                                 ((2, D), f32), ((MAX_K_FUSED,), jnp.bool_)],
                         one_chip)
     assert "tpu_custom_call" in hlo
+    assert _kernels_named(hlo, "dueling_select")
 
 
 def test_posterior_scores_compiles(one_chip):
@@ -93,6 +104,7 @@ def test_posterior_scores_compiles(one_chip):
         lambda a, th: posterior_scores(a, th, interpret=False),
         [((K, D), jnp.float32), ((16, D), jnp.float32)], one_chip)
     assert "tpu_custom_call" in hlo
+    assert _kernels_named(hlo, "dueling_score")
 
 
 @pytest.mark.parametrize("chains", [None, 8], ids=["one_theta", "vmap8"])
@@ -119,3 +131,5 @@ def test_sgld_potential_value_and_grad_compiles(one_chip, m, chains):
          ((m,), f32), ((K, D), f32), ((K,), jnp.bool_), ((m,), f32),
          ((K,), f32)], one_chip)
     assert hlo.count("tpu_custom_call") >= 2      # forward and backward
+    assert _kernels_named(hlo, "sgld_potential_fwd")
+    assert _kernels_named(hlo, "sgld_potential_bwd")
