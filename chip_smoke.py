@@ -176,7 +176,7 @@ class Traffic:
 
 def embed_queries(svc, tokens, mask):
     """Encode a formed batch as its bucket (padding rows have an all-zero
-    mask and are sliced off) so the encoder compiles once per bucket."""
+    mask and are sliced off) so it runs the bucket's AOT encoder."""
     from repro.core import ccft
     from repro.data import routerbench as rb
     from repro.serving import stream
@@ -443,7 +443,7 @@ def one_chip(setup, batches):
     n_progs = sum(v for kk, v in svc.compiled_program_counts().items()
                   if kk.startswith("s_"))
     log(f"compile: streaming service built {n_progs} AOT bucket programs in "
-        f"{compile_s:.3f} s; first pass (eager encoder compiles) "
+        f"{compile_s:.3f} s; first pass (eager ops compile) "
         f"{first_s:.3f} s")
     for n, b, t_route, t_fb in warm:
         log(f"warm batch n={n} bucket={b}: embed+route {t_route:.6f} s, "

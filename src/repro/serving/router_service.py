@@ -262,6 +262,10 @@ class RouterService:
         self.n_routed = 0
         # host totals of the router.* spans (telemetry): compiles per layer
         self._compile_counts = telemetry.CompileCounts()
+        # the query encoder off the bucket ladder (any (B, L), and every
+        # call of a service without buckets): compiled once per shape
+        self._embed_jit = jax.jit(functools.partial(encode, cfg=enc_cfg))
+        self._embed_calls = {"aot": 0, "jit": 0}
         # online representation refresh: the exportable duel-log ring rides
         # next to the policy state (replicated under a mesh) and is folded
         # inside the jitted feedback programs; None when refresh is off —
@@ -581,7 +585,7 @@ class RouterService:
             lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a)),
             tree)
 
-    def _aot(self, fn, *, donate_argnums, avals, shardings=None):
+    def _aot(self, fn, *, avals, donate_argnums=(), shardings=None):
         """Ahead-of-time compile one bucket program. The trace happens here,
         at construction, against abstract operands — first-request latency
         pays zero compile time — and the compiled executable can never
@@ -887,6 +891,23 @@ class RouterService:
                          donate_argnums=STREAM_DONATION["_s_resolve"],
                          avals=v_avals(b), shardings=v_sh)
             for b in self.buckets}
+        # the query encoder per bucket at the configured length: embed
+        # dispatches a compiled executable and makes no program per call.
+        # The weights are operands (replicated under a mesh), never
+        # constants baked into the executable; the rows come out
+        # batch-sharded as route_fused takes its x.
+        if mesh is not None:
+            self.enc_params = jax.device_put(self.enc_params, self._rep_sh)
+        e_sh = None if mesh is None else (
+            (self._rep_sh, self._x_sh, self._x_sh), self._x_sh)
+        seq = self.enc_cfg.max_len
+        self._s_embed = {
+            b: self._aot(functools.partial(encode, cfg=self.enc_cfg),
+                         avals=(self._avals(self.enc_params),
+                                jax.ShapeDtypeStruct((b, seq), jnp.int32),
+                                jax.ShapeDtypeStruct((b, seq), jnp.float32)),
+                         shardings=e_sh)
+            for b in self.buckets}
         # per-(bucket, live-count) mask / zero-pref / unknown-category
         # caches: placed once, reused every call (never donated)
         self._masks, self._zero_prefs, self._neg_cats = {}, {}, {}
@@ -1086,14 +1107,31 @@ class RouterService:
 
     def embed(self, tokens: jax.Array, mask: jax.Array) -> jax.Array:
         """(B, L) tokens and pooling mask -> (B, d) query embeddings, in a
-        ``router.embed`` span with ``bucket`` B and, where the mask is on
-        the host, ``n``, the rows with a token (a device mask is not read,
-        and the span then has no ``n``)."""
+        ``router.embed`` span with ``bucket`` B, ``aot`` (1 when a bucket
+        program served the call) and, where the mask is on the host,
+        ``n``, the rows with a token (a device mask is not read, and the
+        span then has no ``n``).
+
+        A (bucket, ``enc_cfg.max_len``) batch runs the bucket's AOT
+        encoder; any other shape, and every call of a service without
+        buckets, runs ``encode`` under one jit that compiles once per
+        shape."""
+        b = np.shape(tokens)[0]
+        prog = (self._s_embed.get(b) if self.streaming
+                and np.shape(tokens) == (b, self.enc_cfg.max_len) else None)
         live = ({"n": np.count_nonzero(mask.any(axis=1))}
                 if isinstance(mask, np.ndarray) else {})
         with telemetry.span("embed", self._compile_counts, tick=self.tick,
-                            bucket=np.shape(tokens)[0], **live):
-            return encode(self.enc_params, tokens, mask, self.enc_cfg)
+                            bucket=b, aot=int(prog is not None), **live):
+            if prog is None:
+                self._embed_calls["jit"] += 1
+                return self._embed_jit(self.enc_params, tokens, mask)
+            self._embed_calls["aot"] += 1
+            rows = [a if a.dtype == dt else a.astype(dt)
+                    for a, dt in ((tokens, np.int32), (mask, np.float32))]
+            if self.mesh is not None:
+                rows = jax.device_put(rows, self._x_sh)
+            return prog(self.enc_params, *rows)
 
     def route_batch(self, x: jax.Array, prefs: jax.Array | None = None,
                     cats: jax.Array | None = None):
@@ -1332,7 +1370,8 @@ class RouterService:
                "n_folded": int(n_folded), "duel_cost": float(duel_cost),
                "pending": int(pending),
                "compiles": dict(self._compile_counts.compiles),
-               "compile_s": dict(self._compile_counts.compile_s)}
+               "compile_s": dict(self._compile_counts.compile_s),
+               "embed_calls": dict(self._embed_calls)}
         if self.refresh_on:
             out["duels_logged"] = int(logged)
             out["table_swaps"] = self._table_swaps
@@ -1550,7 +1589,8 @@ class RouterService:
                "update_delayed": self._update_delayed,
                "update_masked": self._update_masked,
                "update_pref": self._update_pref,
-               "enqueue": self._enqueue, "resolve": self._resolve}
+               "enqueue": self._enqueue, "resolve": self._resolve,
+               "embed_jit": self._embed_jit}
         if self.dynamic:
             fns.update(pool_set=self._pool_set,
                        pool_retire=self._pool_retire,
@@ -1573,6 +1613,7 @@ class RouterService:
             if self._s_feedback_log is not None:
                 counts["s_feedback_log"] = len(self._s_feedback_log)
             counts["s_resolve"] = len(self._s_resolve)
+            counts["s_embed"] = len(self._s_embed)
         return counts
 
     # -- persistence (posterior + replay + in-flight duels survive restarts) -

@@ -14,6 +14,10 @@ Contracts pinned here (ISSUE 9 acceptance):
     shard-local layout's pow2/divisibility contracts) by raising;
   * the strided ticket encoding of ``enqueue_stream``/``resolve_stream``
     round-trips with masked padding, dedup and staleness intact;
+  * the query encoder runs one AOT program per bucket: a bucket-shaped
+    ``embed`` equals ``encode`` and compiles nothing, any other shape
+    compiles once under the service's jit, and ``embed_calls`` counts
+    both ways;
   * ``env.run(DelaySpec(per_item=True))`` with a constant lag is
     bit-identical to the per-tick lag, and raises for policies without a
     masked fold;
@@ -382,6 +386,109 @@ def test_feedback_direct_resolves_streaming_ring():
     assert svc.pending_count() == 4
     svc.feedback_direct(x, a1, a2, jnp.ones((4,)), tickets=t)
     assert svc.pending_count() == 0
+
+
+# ---------------------------------------------------------------------------
+# the query encoder's bucket programs (RouterService.embed)
+# ---------------------------------------------------------------------------
+
+SEQ = 8                                   # _service's encoder max_len
+
+
+def _queries(b, seq=SEQ, live=None):
+    """(b, seq) int32 tokens and a float32 pooling mask whose first
+    ``live`` rows (all, by default) hold a query."""
+    tok = (np.arange(b * seq).reshape(b, seq) * 7 % 50).astype(np.int32)
+    mask = np.zeros((b, seq), np.float32)
+    mask[:b if live is None else live] = 1.0
+    return tok, mask
+
+
+@pytest.mark.parametrize("bucket", [8, 16])
+def test_embed_bucket_program_matches_encode(bucket):
+    """A bucket-shaped batch runs the bucket's AOT encoder and gives what
+    ``encode`` gives, padding rows included."""
+    from repro.encoder.model import encode
+    svc = _service(buckets=(8, 16))
+    tok, mask = _queries(bucket, live=bucket - 3)
+    got = svc.embed(tok, mask)
+    want = encode(svc.enc_params, tok, mask, svc.enc_cfg)
+    assert got.shape == (bucket, DIM)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    assert svc.service_stats()["embed_calls"] == {"aot": 1, "jit": 0}
+
+
+def test_embed_at_every_bucket_compiles_nothing(assert_flat):
+    """The encoder is compiled per bucket at construction: repeated embeds
+    at every bucket, host or device rows, compile no program and the
+    ``router.embed`` spans count no compile."""
+    svc = _service(buckets=(4, 8, 16))
+    assert svc.compiled_program_counts()["s_embed"] == 3
+    with assert_flat(svc, note="bucket embeds") as flat:
+        for _ in range(2):
+            for b in (4, 8, 16):
+                tok, mask = _queries(b)
+                svc.embed(tok, mask)
+                svc.embed(jnp.asarray(tok), jnp.asarray(mask))
+                flat.check(f"bucket={b}")
+    stats = svc.service_stats()
+    assert stats["compiles"] == {"embed": 0}
+    assert stats["compile_s"] == {"embed": 0.0}
+    assert stats["embed_calls"] == {"aot": 12, "jit": 0}
+
+
+@pytest.mark.parametrize("buckets,b,seq", [
+    ((8, 16), 5, SEQ),       # a batch size off the ladder
+    ((8, 16), 8, SEQ // 2),  # a bucket, at another sequence length
+    (None, 8, SEQ),          # a service without buckets
+])
+def test_embed_off_ladder_compiles_once_per_shape(buckets, b, seq):
+    """Any other shape runs ``encode`` under the service's one jit: it
+    compiles on its first call and never after."""
+    from repro.encoder.model import encode
+    svc = _service(buckets=buckets)
+    tok, mask = _queries(b, seq)
+    jit0 = svc.compiled_program_counts()["embed_jit"]
+    got = svc.embed(tok, mask)
+    first = svc.service_stats()["compiles"]["embed"]
+    assert first >= 1
+    assert svc.compiled_program_counts()["embed_jit"] == jit0 + 1
+    for _ in range(2):
+        svc.embed(tok, mask)
+    assert svc.service_stats()["compiles"]["embed"] == first
+    assert svc.compiled_program_counts()["embed_jit"] == jit0 + 1
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(encode(svc.enc_params, tok, mask, svc.enc_cfg)),
+        atol=1e-5)
+    assert svc.service_stats()["embed_calls"] == {"aot": 0, "jit": 3}
+
+
+def test_embed_calls_count_aot_and_jit():
+    svc = _service(buckets=(8, 16))
+    for b, seq in [(8, SEQ), (16, SEQ), (5, SEQ), (8, SEQ), (16, 4)]:
+        svc.embed(*_queries(b, seq))
+    assert svc.service_stats()["embed_calls"] == {"aot": 3, "jit": 2}
+
+
+def test_embed_programs_survive_restore(tmp_path):
+    """``restore`` brings back the posterior, ring and clock; the encoder
+    is not in the checkpoint, so its bucket programs keep serving the
+    restored service, with nothing to compile."""
+    svc, svc2 = _service(buckets=(8,)), _service(buckets=(8,))
+    tok, mask = _queries(8, live=6)
+    _, _, t = svc.route_stream(svc.embed(tok, mask)[:6])
+    svc.save(str(tmp_path))
+    progs = dict(svc2._s_embed)
+    svc2.restore(str(tmp_path))
+    assert svc2._s_embed == progs
+    x = svc2.embed(tok, mask)
+    np.testing.assert_array_equal(np.asarray(x),
+                                  np.asarray(svc.embed(tok, mask)))
+    stats = svc2.service_stats()
+    assert stats["embed_calls"] == {"aot": 1, "jit": 0}
+    assert stats["compiles"]["embed"] == 0
+    assert int(svc2.feedback_stream(t, jnp.ones((6,)))) == 6
 
 
 # ---------------------------------------------------------------------------

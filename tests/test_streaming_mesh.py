@@ -184,3 +184,29 @@ def test_mesh_streaming_checkpoint_roundtrip(tmp_path):
     np.testing.assert_array_equal(outs[0][0], outs[1][0])
     np.testing.assert_array_equal(outs[0][1], outs[1][1])
     _state_eq(outs[0][2], outs[1][2])
+
+
+def test_mesh_embed_bucket_program_matches_single_device():
+    """Under the mesh each bucket's encoder runs with the weights
+    replicated and the rows batch-sharded: it gives the single-device
+    program's embeddings, already sharded as ``route_stream`` takes its
+    queries, with no collective and nothing compiled per call."""
+    svc1 = _service(buckets=(8, 16))
+    svcm = _service(buckets=(8, 16), mesh=_mesh())
+    for b in (8, 16):
+        tok = (np.arange(b * 8).reshape(b, 8) * 7 % 50).astype(np.int32)
+        mask = np.ones((b, 8), np.float32)
+        mask[b - 2:] = 0.0
+        xm = svcm.embed(tok, mask)
+        np.testing.assert_allclose(np.asarray(xm),
+                                   np.asarray(svc1.embed(tok, mask)),
+                                   atol=1e-5)
+        assert xm.sharding == svcm._x_sh
+        hlo = svcm._s_embed[b].as_text()
+        for op in SHUFFLE + REDUCE:
+            assert op not in hlo, f"embed[{b}] lowered a {op}"
+        _, _, t = svcm.route_stream(xm)
+        assert int(svcm.feedback_stream(t, jnp.ones((b,)))) == b
+    stats = svcm.service_stats()
+    assert stats["embed_calls"] == {"aot": 2, "jit": 0}
+    assert stats["compiles"]["embed"] == 0

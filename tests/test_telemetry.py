@@ -4,8 +4,9 @@
 Pinned here: one ``router.embed`` / ``router.route`` / ``router.feedback``
 host event per call in a profiler trace, each carrying ``tick``, ``n`` and
 ``bucket`` and, set as it closes, the compile-path metadata; one formed
-batch's embed and route share their tick; a first call at a new batch size
-compiles and a repeat of the route program does not; with no profiler
+batch's embed and route share their tick; a bucket-shaped embed runs its
+AOT program and compiles nothing, while the route's eager work compiles at
+a new batch size and not on a repeat; with no profiler
 recording the span stack empties after every call and ``service_stats()``
 still counts compiles; the spans leave ``compiled_program_counts()`` as it
 was.
@@ -110,8 +111,10 @@ def test_first_size_compiles_and_a_repeat_route_does_not(traced):
     _, events = traced
     st = [s for _, s in events]
     embed, route = st[0::3], st[1::3]
-    assert embed[0]["compiles"] > 0 and route[0]["compiles"] > 0
-    assert embed[0]["compile_s"] > 0.0
+    # the encoder was compiled per bucket at construction
+    assert all(e["aot"] == 1 for e in embed)
+    assert all(e["compiles"] == 0 and e["compile_s"] == 0.0 for e in embed)
+    assert route[0]["compiles"] > 0
     assert route[1]["compiles"] == 0 and route[1]["compile_s"] == 0.0
     assert route[2]["compiles"] > 0          # 3 live rows: a new size
 
@@ -140,8 +143,11 @@ def test_unrecorded_spans_close_and_still_count_compiles():
         assert _batch(svc, n) == [(), (), ()]
     stats = svc.service_stats()
     assert set(stats["compiles"]) == {"embed", "route", "feedback"}
-    assert all(c > 0 for c in stats["compiles"].values())
-    assert all(s > 0.0 for s in stats["compile_s"].values())
+    assert all(stats["compiles"][k] > 0 for k in ("route", "feedback"))
+    assert all(stats["compile_s"][k] > 0.0 for k in ("route", "feedback"))
+    # bucket-shaped embeds run the AOT encoder: nothing to compile
+    assert stats["compiles"]["embed"] == 0
+    assert stats["compile_s"]["embed"] == 0.0
     assert stats["n_routed"] == 8 and stats["n_folded"] == 8
     assert svc.compiled_program_counts() == before
 
