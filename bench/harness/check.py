@@ -187,9 +187,10 @@ def numbers(ev: Evidence, final: dict) -> dict:
     program's replay ring (x, a1, a2, y, pref, t) and its
     ``service_stats()`` after the window."""
     s = ev.sess
-    enc_d = s.enc["hidden_size"]
+    enc_d = s.encoder.embed_dim(s.enc)
     rows = _sampled_rows(ev)
-    x_ref = ref.encode_rows(s.params, s.reqs.tokens[rows])
+    x_ref = ref.encode_rows(s.encoder.reference, s.params,
+                            s.reqs.tokens[rows])
     x_prog = np.stack([ev.row(int(r))[0][:enc_d] for r in rows])
     enc_err = float(np.max(np.linalg.norm(x_prog - x_ref, axis=1)))
 
@@ -223,8 +224,9 @@ def control_numbers(ev: Evidence, enc_mode: str, kernel_mode: str) -> dict:
     s = ev.sess
     rows = _sampled_rows(ev)
     tok = s.reqs.tokens[rows]
-    x_ref = ref.encode_rows(s.params, tok)
-    x_ctl = ref.encode_rows(s.params, tok, mode=enc_mode)
+    x_ref = ref.encode_rows(s.encoder.reference, s.params, tok)
+    x_ctl = ref.encode_rows(s.encoder.reference, s.params, tok,
+                            mode=enc_mode)
     enc_err = float(np.max(np.linalg.norm(x_ctl - x_ref, axis=1)))
 
     picked = _sampled_calls(ev)

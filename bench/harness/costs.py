@@ -1,22 +1,11 @@
 """Operations and bytes the router's work needs, from its shapes.
 
-Counted from the algorithm, never from the program: the encoder block as
-it runs (pre-norm attention + a three-matrix gated MLP), the SGLD
-potential kernels and the dueling selection. Used for roofline shares and
-``serve_mfu``.
+Counted from the algorithm, never from the program: the SGLD potential
+kernels and the dueling selection (the encoder's count is its module's
+``flops_per_query``, beside the block it counts). Used for roofline shares
+and ``serve_mfu``.
 """
 from __future__ import annotations
-
-
-def encoder_flops_per_query(enc: dict, seq_len: int) -> float:
-    """Forward FLOPs of one query of ``seq_len`` tokens: per token and
-    layer, the q/k/v/o projections (4 d^2 MACs), the gated MLP (3 d d_ff
-    MACs) and attention's scores and values (2 L d MACs); 2 FLOPs a MAC.
-    Norms, softmax, the embedding gather and pooling are not counted."""
-    d, dff, layers = enc["hidden_size"], enc["intermediate_size"], \
-        enc["num_hidden_layers"]
-    macs = 4 * d * d + 3 * d * dff + 2 * seq_len * d
-    return 2.0 * macs * layers * seq_len
 
 
 def sgld_kernel_cost(kind: str, m: int, k: int, d: int, chains: int):

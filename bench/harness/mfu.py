@@ -1,8 +1,9 @@
 """The whole routed step's share of the chip's bf16 peak: the encoder's
 forward FLOPs for every request routed in the window (live rows only, at
-the configuration's widths), plus each formed batch's SGLD refresh (two
-samples of every chain, ``sgld_steps`` forward and backward potential
-evaluations) and dueling selection, over the window and the peak."""
+the configuration's widths, as its encoder module counts them), plus each
+formed batch's SGLD refresh (two samples of every chain, ``sgld_steps``
+forward and backward potential evaluations) and dueling selection, over
+the window and the peak."""
 from . import costs
 
 
@@ -15,7 +16,7 @@ def serve_mfu(run) -> float | None:
     step = sum(costs.sgld_kernel_cost(kind, fg.sgld_minibatch, k, s.d,
                                       fg.n_chains)[0]
                for kind in ("fwd", "bwd"))
-    flops = costs.encoder_flops_per_query(s.enc, s.seq_len) * rec["routed"]
+    flops = s.encoder.flops_per_query(s.enc, s.seq_len) * rec["routed"]
     for n, _ in rec["batches"]:
         flops += 2 * fg.sgld_steps * step + costs.select_flops(n, k, s.d)
     return 100.0 * flops / rec["window_s"] / run.peaks.flops_bf16

@@ -59,72 +59,28 @@ def einsum(spec: str, a, b, mode: str = "highest"):
 
 
 # ---------------------------------------------------------------------------
-# Query encoder
+# Query encoder (the block itself is the encoder module's ``reference``)
 # ---------------------------------------------------------------------------
 
-def _rms(x, scale, eps=1e-6):
+@functools.lru_cache(maxsize=None)
+def _jitted(encode):
     import jax
-    import jax.numpy as jnp
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1.0 + scale)
+    return jax.jit(encode, static_argnames=("mode",))
 
 
-def _rope(x, theta=10000.0):
-    """Rotary positions 0..L-1 on the two halves of each head."""
-    import jax.numpy as jnp
-    hd, seq = x.shape[-1], x.shape[1]
-    freqs = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
-    ang = np.arange(seq, dtype=np.float32)[:, None] * freqs     # (L, hd/2)
-    cos = jnp.asarray(np.cos(ang))[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang))[None, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
-
-
-@functools.partial(__import__("jax").jit, static_argnames=("mode",))
-def encode(params, tokens, mode: str = "highest"):
-    """L2-normalised sentence embeddings (n, d) of fixed-length token rows:
-    pre-norm (RMSNorm) bidirectional attention with rotary positions and a
-    gated (SiLU) MLP per layer, a final RMSNorm, mean pooling over every
-    position. The layers run one after another over the stacked weights."""
-    import jax
-    import jax.numpy as jnp
-    x = params["embed"][tokens]
-    u = params["units"]["0"]
-    layers = u["norm1"].shape[0]
-    hd = u["mixer"]["wq"].shape[-1]
-    for i in range(layers):
-        w = jax.tree.map(lambda a: a[i], u)
-        h = _rms(x, w["norm1"])
-        q = _rope(einsum("bsd,dhk->bshk", h, w["mixer"]["wq"], mode))
-        k = _rope(einsum("bsd,dhk->bshk", h, w["mixer"]["wk"], mode))
-        v = einsum("bsd,dhk->bshk", h, w["mixer"]["wv"], mode)
-        s = einsum("bshd,bthd->bhst", q, k, mode) * hd ** -0.5
-        p = jax.nn.softmax(s, axis=-1)
-        o = einsum("bhst,bthd->bshd", p, v, mode)
-        x = x + einsum("bshk,hkd->bsd", o, w["mixer"]["wo"], mode)
-        h = _rms(x, w["norm2"])
-        g = jax.nn.silu(einsum("bsd,df->bsf", h, w["ffn"]["w1"], mode)) \
-            * einsum("bsd,df->bsf", h, w["ffn"]["w3"], mode)
-        x = x + einsum("bsf,fd->bsd", g, w["ffn"]["w2"], mode)
-    x = _rms(x, params["final_norm"])
-    pooled = jnp.mean(x, axis=1)
-    return pooled / jnp.maximum(
-        jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)
-
-
-def encode_rows(params, tokens: np.ndarray, mode: str = "highest",
+def encode_rows(encode, params, tokens: np.ndarray, mode: str = "highest",
                 block: int = 64) -> np.ndarray:
-    """``encode`` over (n, L) host tokens in blocks of rows, so that the
-    reference fits beside the program's buffers."""
+    """An encoder module's ``reference`` (``encode``) over (n, L) host
+    tokens in blocks of rows, so that the reference fits beside the
+    program's buffers."""
+    fn = _jitted(encode)
     out = []
     for i in range(0, tokens.shape[0], block):
         t = tokens[i:i + block]
         pad = block - t.shape[0]
         if pad:
             t = np.concatenate([t, np.zeros((pad, t.shape[1]), t.dtype)])
-        out.append(np.asarray(encode(params, t, mode=mode))[:block - pad])
+        out.append(np.asarray(fn(params, t, mode=mode))[:block - pad])
     return np.concatenate(out)
 
 

@@ -26,7 +26,8 @@ import time
 import numpy as np
 
 from . import traffic as tr
-from .weights import encoder_widths, make_weights, std_metadata
+from .cell import encoder as load_encoder
+from .weights import make_weights, std_metadata
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -158,7 +159,6 @@ class Session:
                  spans: Spans):
         from repro.core import fgts
         from repro.data.pool import build_entries
-        from repro.encoder import EncoderConfig
         from repro.serving import RouterService, RouterServiceConfig
 
         self.config, self.spans = config, spans
@@ -166,8 +166,9 @@ class Session:
         self.log: list = []          # RouteCall and Delivery, in call order
         self.n_calls = 0
         self.seeds = sub_seeds(seed)
-        self.enc = encoder_widths(config["encoder"])
-        self.seq_len = int(config["encoder"]["max_position_embeddings"])
+        self.encoder = load_encoder(config["encoder"])
+        self.enc = self.encoder.widths(config["encoder"])
+        self.seq_len = int(self.encoder.seq_len(self.enc))
         sv = config["serving"]
         self.buckets = tuple(sv["buckets"])
         self.max_wait = float(sv["max_wait_s"])
@@ -176,16 +177,11 @@ class Session:
         self.pool = make_pool(config, seed)
         k = len(self.pool.names)
         self.params, self.a_emb = make_weights(
-            self.seeds["weights"], self.enc, k, self.pool.meta)
+            self.seeds["weights"], self.encoder, self.enc, k, self.pool.meta)
         self.n_meta = 0 if self.pool.meta is None else self.pool.meta.shape[1]
-        self.d = self.enc["hidden_size"] + self.n_meta
-        enc_cfg = EncoderConfig(
-            vocab_size=self.enc["vocab_size"],
-            d_model=self.enc["hidden_size"],
-            n_layers=self.enc["num_hidden_layers"],
-            n_heads=self.enc["num_attention_heads"],
-            d_ff=self.enc["intermediate_size"], max_len=self.seq_len,
-            name=config["name"])
+        self.d = self.encoder.embed_dim(self.enc) + self.n_meta
+        enc_cfg = self.encoder.service_encoder(self.enc, self.seq_len,
+                                               config["name"])
         fg = config["fgts"]
         self.fgts_cfg = fgts.FGTSConfig(
             n_models=k, dim=self.d, horizon=fg["horizon"], eta=fg["eta"],

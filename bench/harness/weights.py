@@ -1,41 +1,19 @@
 """Weights and the arm table, made on the device from the seed in one
 jitted call, in the type they are served in (float32).
 
-The encoder's tree has the layout the program's encoder reads (one stacked
-unit of a pre-norm bidirectional-attention block with a gated MLP); the
-benchmark makes it so that the reference can use the same numbers without
-taking anything the program made.
+The encoder's tree is made by the configuration's encoder module
+(``harness/encoders/<arch>.py``, ``make_params``) in the layout the
+program's encoder reads; the benchmark makes it so that the reference can
+use the same numbers without taking anything the program made.
 """
 from __future__ import annotations
 
 import functools
+import json
 
 import numpy as np
 
-ENC_KEYS = {   # published key -> the benchmark's name for it
-    "dim": "hidden_size", "hidden_size": "hidden_size",
-    "hidden_dim": "intermediate_size",
-    "intermediate_size": "intermediate_size",
-    "n_layers": "num_hidden_layers", "num_hidden_layers": "num_hidden_layers",
-    "n_heads": "num_attention_heads",
-    "num_attention_heads": "num_attention_heads",
-    "vocab_size": "vocab_size",
-    "max_position_embeddings": "max_position_embeddings",
-}
-
-
-def encoder_widths(published: dict) -> dict:
-    """The encoder's widths under one set of names, from a config that
-    uses either DistilBERT's or BERT's key names."""
-    out = {}
-    for k, v in published.items():
-        if k in ENC_KEYS and isinstance(v, int):
-            out[ENC_KEYS[k]] = v
-    missing = {"hidden_size", "intermediate_size", "num_hidden_layers",
-               "num_attention_heads", "vocab_size"} - set(out)
-    if missing:
-        raise KeyError(f"encoder config lacks {sorted(missing)}")
-    return out
+N_ENCODER_KEYS = 11    # the seed's key splits into these and the arm table's
 
 
 def std_metadata(perf: np.ndarray, cost: np.ndarray) -> np.ndarray:
@@ -48,34 +26,17 @@ def std_metadata(perf: np.ndarray, cost: np.ndarray) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=None)
-def _maker(d, dff, layers, heads, vocab, k, d_arm):
+def _maker(encoder, widths: str, k, d_arm):
     import jax
     import jax.numpy as jnp
 
-    hd = d // heads
+    w = json.loads(widths)
+    d = encoder.embed_dim(w)
 
     def make(key, meta):
-        ks = jax.random.split(key, 12)
-        n = jax.random.normal
-        units = {"0": {
-            "norm1": 0.1 * n(ks[0], (layers, d)),
-            "mixer": {
-                "wq": n(ks[1], (layers, d, heads, hd)) * d ** -0.5,
-                "wk": n(ks[2], (layers, d, heads, hd)) * d ** -0.5,
-                "wv": n(ks[3], (layers, d, heads, hd)) * d ** -0.5,
-                "wo": n(ks[4], (layers, heads, hd, d)) * d ** -0.5,
-            },
-            "norm2": 0.1 * n(ks[5], (layers, d)),
-            "ffn": {
-                "w1": n(ks[6], (layers, d, dff)) * d ** -0.5,
-                "w3": n(ks[7], (layers, d, dff)) * d ** -0.5,
-                "w2": n(ks[8], (layers, dff, d)) * dff ** -0.5,
-            },
-        }}
-        params = {"embed": n(ks[9], (vocab, d)) * d ** -0.5,
-                  "units": units,
-                  "final_norm": 0.1 * n(ks[10], (d,))}
-        sem = n(ks[11], (k, d))
+        ks = jax.random.split(key, N_ENCODER_KEYS + 1)
+        params = encoder.make_params(ks[:N_ENCODER_KEYS], w)
+        sem = jax.random.normal(ks[N_ENCODER_KEYS], (k, d))
         sem = sem / jnp.linalg.norm(sem, axis=1, keepdims=True)
         a_emb = jnp.concatenate([sem, meta], axis=1) if d_arm > d else sem
         return params, a_emb
@@ -83,14 +44,14 @@ def _maker(d, dff, layers, heads, vocab, k, d_arm):
     return jax.jit(make)
 
 
-def make_weights(seed: int, enc: dict, k: int, meta: np.ndarray | None):
-    """(encoder params, (K, d_arm) arm table) on the default device.
-    ``meta`` (K, m) columns are appended to the arm table when given."""
+def make_weights(seed: int, encoder, w: dict, k: int,
+                 meta: np.ndarray | None):
+    """(encoder params, (K, d_arm) arm table) on the default device, for
+    the encoder module ``encoder`` at widths ``w``. ``meta`` (K, m)
+    columns are appended to the arm table when given."""
     import jax
     import jax.numpy as jnp
-    d = enc["hidden_size"]
     m = np.zeros((k, 0), np.float32) if meta is None else meta
-    fn = _maker(d, enc["intermediate_size"], enc["num_hidden_layers"],
-                enc["num_attention_heads"], enc["vocab_size"], k,
-                d + m.shape[1])
+    fn = _maker(encoder, json.dumps(w, sort_keys=True), k,
+                encoder.embed_dim(w) + m.shape[1])
     return fn(jax.random.PRNGKey(seed), jnp.asarray(m))

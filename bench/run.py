@@ -3,9 +3,10 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell (``BENCHMARK.json`` -> ``configs/`` + ``traffic/``) is served open
-loop for ``--seconds`` after set-up, and what the timed path produced is
-compared with the plain references (``harness/check.py``). With
+The cell (``BENCHMARK.json`` -> ``configs/`` + ``traffic/``, and the
+encoder module its configuration names, ``harness/encoders/``) is served
+open loop for ``--seconds`` after set-up, and what the timed path produced
+is compared with the plain references (``harness/check.py``). With
 ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` a profiler trace of the window gives its per-layer metrics
 (``metrics/<name>.py``) and a breakdown. The last line of standard output

@@ -1,5 +1,6 @@
 """The yardstick kept with the benchmark: the seeded arrival generator, the
-peaks table, the SGLD cost model and the encoder's FLOP count."""
+peaks table, the SGLD cost model and the encoder's FLOP count (its
+module's ``flops_per_query``)."""
 import os
 import sys
 
@@ -78,9 +79,10 @@ def test_sgld_cost_model_is_the_programs_count():
 
 @pytest.mark.parametrize("layers,want_gflop", [(6, 14.80), (12, 29.60)])
 def test_encoder_flops_closed_form(layers, want_gflop):
+    from harness.cell import encoder
     enc = {"hidden_size": 768, "intermediate_size": 3072,
            "num_hidden_layers": layers}
-    f = costs.encoder_flops_per_query(enc, 128)
+    f = encoder({"arch": "rope_bidir"}).flops_per_query(enc, 128)
     # per token and layer: 2 (4 d^2 + 3 d d_ff + 2 L d) = 19,267,584 FLOPs
     assert f == 19_267_584 * layers * 128
     assert abs(f / 1e9 - want_gflop) < 0.01
